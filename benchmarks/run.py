@@ -26,6 +26,8 @@ def main(argv: list[str] | None = None) -> None:
             raise SystemExit("--json requires an output path")
         del argv[i:i + 2]
     want = argv or ["table1", "table2", "table3", "roofline"]
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import (bench_scheduler, roofline, table1_profiling,
                             table2_stop_restart, table3_scheduler_sim)
     mods = {"table1": table1_profiling, "table2": table2_stop_restart,

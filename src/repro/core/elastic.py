@@ -1,22 +1,27 @@
 """Elastic checkpoint–stop–restart trainer (paper §5–6).
 
-Drives any model exposing ``loss(params, batch)`` through training segments
-at varying worker counts w.  Per-worker minibatch m stays fixed (global
-batch = m*w, §5), the LR rescales linearly on resize (eq. 7), and LR decay
-boundaries stay pinned to *epochs* so they shift in step-space with the
-batch size, exactly as the paper describes.  Stop and restart costs are
-measured, not assumed — benchmarks/table2_stop_restart.py reports them.
+Drives any model exposing ``loss(params, batch, sh=None)`` through training
+segments at varying worker counts w.  Per-worker minibatch m stays fixed
+(global batch = m*w, §5), the LR rescales linearly on resize (eq. 7), and LR
+decay boundaries stay pinned to *epochs* so they shift in step-space with the
+batch size, exactly as the paper describes.  A segment at w workers runs on a
+data mesh of w devices when that many exist (batch sharded, state
+replicated, gradients exchanged by ``grad_exchange``); otherwise it runs the
+global batch on one device, and ``SegmentRecord.devices`` says which
+happened.  Stop and restart costs are measured, not assumed —
+benchmarks/table2_stop_restart.py reports them.
 """
 from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 
 from repro.checkpoint.store import CheckpointStore
+from repro.engine.steps import make_data_parallel_step
+from repro.launch.mesh import make_data_mesh
 from repro.optim.optimizers import Optimizer
 from repro.optim.schedule import rescale_lr
 
@@ -24,12 +29,17 @@ from repro.optim.schedule import rescale_lr
 @dataclasses.dataclass
 class SegmentRecord:
     w: int
+    devices: int           # devices the segment actually ran on
     steps: int
     epochs: float
+    lr: float              # LR at the segment's first step (eq. 7)
     losses: list           # (global_step, cumulative_epoch, loss)
     seconds: float
+    first_step_seconds: float   # first step to ready, compile included
+    step_seconds: float    # mean per step after the first (nan if 1 step)
     restore_seconds: float
     save_seconds: float
+    state: dict            # final state, as saved
 
 
 class ElasticTrainer:
@@ -37,7 +47,8 @@ class ElasticTrainer:
                  ckpt: CheckpointStore, *, base_lr_1w: float,
                  m_per_worker: int = 128,
                  decay_epochs: tuple = (100, 150), decay_factor: float = 0.1,
-                 dataset_size: int | None = None):
+                 dataset_size: int | None = None,
+                 grad_exchange: str | None = None):
         self.model = model
         self.opt = optimizer
         self.data = data
@@ -47,14 +58,8 @@ class ElasticTrainer:
         self.decay_epochs = decay_epochs
         self.decay_factor = decay_factor
         self.dataset = dataset_size or getattr(data, "size", 50_000)
-
-        def train_step(params, opt_state, batch, lr):
-            loss, grads = jax.value_and_grad(self.model.loss)(params, batch)
-            new_params, new_opt = self.opt.update(grads, opt_state, params,
-                                                  lr)
-            return loss, new_params, new_opt
-
-        self._step = jax.jit(train_step)
+        self.grad_exchange = grad_exchange
+        self._steps: dict[int, tuple] = {}
 
     # ------------------------------------------------------------ state ----
     def fresh_state(self, key=None) -> dict:
@@ -73,6 +78,17 @@ class ElasticTrainer:
                 lr *= self.decay_factor
         return lr
 
+    def step_for(self, w: int):
+        """-> (jitted step, replicated sharding, batch sharding) that a
+        segment at w workers runs: w devices when that many exist, else
+        one."""
+        n = w if jax.device_count() >= w else 1
+        if n not in self._steps:
+            mesh = make_data_mesh(n)
+            self._steps[n] = make_data_parallel_step(
+                self.model, self.opt, mesh, self.grad_exchange)
+        return self._steps[n]
+
     # ---------------------------------------------------------- segments ---
     def train_segment(self, w: int, n_steps: int, *, resume: bool = True,
                       log_every: int = 10) -> SegmentRecord:
@@ -82,6 +98,8 @@ class ElasticTrainer:
             state, meta, restore_s = self.ckpt.restore(template)
         else:
             state = self.fresh_state()
+        step, rep, data_sharding = self.step_for(w)
+        devices = len(rep.device_set)
 
         global_batch = self.m * w
         epochs_per_step = global_batch / self.dataset
@@ -89,24 +107,34 @@ class ElasticTrainer:
         t0 = time.perf_counter()
         step0 = int(state["step"])
         epoch = float(state["epoch"])
-        params, opt_state = state["params"], state["opt"]
+        lr0 = self._lr(w, epoch)
+        train_state = jax.device_put(
+            {"params": state["params"], "opt": state["opt"]}, rep)
+        first_s = 0.0
         for i in range(n_steps):
             gstep = step0 + i
-            batch = self.data.batch(gstep, global_batch)
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            lr = self._lr(w, epoch)
-            loss, params, opt_state = self._step(params, opt_state, batch,
-                                                 lr)
+            batch = jax.device_put(self.data.batch(gstep, global_batch),
+                                   data_sharding)
+            train_state, loss = step(train_state, batch,
+                                     self._lr(w, epoch))
+            if i == 0:
+                jax.block_until_ready((train_state, loss))
+                first_s = time.perf_counter() - t0
             epoch += epochs_per_step
             if i % log_every == 0 or i == n_steps - 1:
                 losses.append((gstep, epoch, float(loss)))
+        jax.block_until_ready(train_state)
         seconds = time.perf_counter() - t0
+        step_s = ((seconds - first_s) / (n_steps - 1) if n_steps > 1
+                  else float("nan"))
 
-        state = {"params": params, "opt": opt_state,
+        state = {**train_state,
                  "step": jnp.asarray(step0 + n_steps, jnp.int32),
                  "epoch": jnp.asarray(epoch, jnp.float32)}
         save_s = self.ckpt.save(step0 + n_steps, state,
                                 meta={"w": w, "epoch": epoch})
-        return SegmentRecord(w=w, steps=n_steps, epochs=epoch,
-                             losses=losses, seconds=seconds,
-                             restore_seconds=restore_s, save_seconds=save_s)
+        return SegmentRecord(w=w, devices=devices, steps=n_steps,
+                             epochs=epoch, lr=lr0, losses=losses,
+                             seconds=seconds, first_step_seconds=first_s,
+                             step_seconds=step_s, restore_seconds=restore_s,
+                             save_seconds=save_s, state=state)

@@ -16,6 +16,7 @@ import numpy as np
 from repro.configs import get_config, get_smoke_config
 from repro.configs.shapes import InputShape
 from repro.data.synthetic import TokenStream
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import spec as pspec
 from repro.models.registry import build_model, decode_window
 
@@ -68,6 +69,7 @@ def main(argv=None):
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--new-tokens", type=int, default=16)
     args = ap.parse_args(argv)
+    use_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     gen, dt = serve(cfg, batch=args.batch, prompt_len=args.prompt_len,
                     new_tokens=args.new_tokens)

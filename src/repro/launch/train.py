@@ -4,9 +4,11 @@ Trains any ``--arch`` (full or ``--smoke`` reduced config) on the synthetic
 token pipeline with AdamW + warmup-cosine, checkpointing through the elastic
 store.  ``--workers`` sets the data-parallel worker count the scheduler
 allocated: per-worker batch m stays fixed, global batch = m * workers, LR
-linearly rescaled (paper eq. 7).  With multiple real devices and
-``--grad-exchange ring|doubling_halving`` the gradient exchange runs the
-paper's explicit algorithm under shard_map instead of implicit GSPMD psum.
+linearly rescaled (paper eq. 7).  When ``--workers`` devices exist the step
+runs on a data mesh of that many, batch sharded: GSPMD inserts XLA's
+all-reduce, or ``--grad-exchange ring|doubling_halving`` runs the paper's
+explicit algorithm under shard_map.  With fewer devices the global batch
+runs on one.
 
   PYTHONPATH=src python -m repro.launch.train --arch qwen2.5-3b --smoke \
       --steps 100 --workers 4
@@ -22,7 +24,9 @@ import jax.numpy as jnp
 from repro.configs import get_config, get_smoke_config
 from repro.checkpoint.store import CheckpointStore
 from repro.data.synthetic import TokenStream
-from repro.engine.steps import make_train_step, init_train_state
+from repro.engine.steps import make_data_parallel_step, init_train_state
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_data_mesh
 from repro.models.registry import build_model
 from repro.optim.optimizers import adamw
 from repro.optim.schedule import warmup_cosine, rescale_lr
@@ -44,6 +48,7 @@ def main(argv=None):
                     choices=[None, "ring", "doubling_halving"])
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
@@ -54,20 +59,12 @@ def main(argv=None):
     sched = warmup_cosine(base_lr, warmup=min(20, args.steps // 5 + 1),
                           total=args.steps)
 
-    n_dev = jax.device_count()
-    if args.grad_exchange and n_dev > 1:
-        from functools import partial
-        from jax.sharding import PartitionSpec as P
-        from repro.launch.mesh import make_data_mesh
-        mesh = make_data_mesh(n_dev)
-        step_fn = make_train_step(model, opt,
-                                  grad_exchange=args.grad_exchange)
-        jitted = jax.jit(jax.shard_map(
-            step_fn, mesh=mesh,
-            in_specs=(P(), {"tokens": P("data"), "labels": P("data")}, P()),
-            out_specs=(P(), P()), check_vma=False))
-    else:
-        jitted = jax.jit(make_train_step(model, opt))
+    # one device per worker when that many exist, else the whole global
+    # batch on one device
+    n = args.workers if jax.device_count() >= args.workers else 1
+    jitted, rep, data_sharding = make_data_parallel_step(
+        model, opt, make_data_mesh(n), args.grad_exchange)
+    print(f"{args.workers} worker(s) on {n} device(s)")
 
     state = init_train_state(model, opt)
     store = CheckpointStore(args.ckpt_dir) if args.ckpt_dir else None
@@ -76,12 +73,12 @@ def main(argv=None):
         state, meta, secs = store.restore(state)
         step0 = store.latest_step()
         print(f"restored step {step0} in {secs:.2f}s (meta={meta})")
+    state = jax.device_put(state, rep)
 
     t0 = time.perf_counter()
     first_loss = None
     for i in range(step0, step0 + args.steps):
-        batch = data.batch(i, global_batch)
-        batch = {k: jnp.asarray(v) for k, v in batch.items()}
+        batch = jax.device_put(data.batch(i, global_batch), data_sharding)
         state, loss = jitted(state, batch, jnp.float32(sched(i)))
         if first_loss is None:
             first_loss = float(loss)

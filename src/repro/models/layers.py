@@ -2,9 +2,9 @@
 
 Attention is *query-chunked* (flash-style at the XLA level): scores are only
 ever materialized for one query block at a time, so 32k prefill never builds
-an S x S tensor.  On real TPUs the Pallas kernel in
-``repro.kernels.swa_attention`` replaces the inner block; the XLA path here
-is the portable reference and the one the dry-run lowers.
+an S x S tensor.  This XLA path is what every model runs and what the
+dry-run lowers.  The Pallas kernel in ``repro.kernels.swa_attention``
+implements the same block for the TPU but is not wired into it.
 """
 from __future__ import annotations
 

@@ -2,9 +2,10 @@
 
 SGD+momentum is the paper's optimizer (ResNet/CIFAR); AdamW serves the LLM
 architectures.  States are pytrees mirroring the params, so checkpointing
-and elastic restarts treat them uniformly.  On TPU the flat-buffer update is
-handled by the fused Pallas kernel (repro.kernels.fused_update); these
-jnp implementations are the portable reference path.
+and elastic restarts treat them uniformly.  These jnp updates are what the
+train step runs.  The fused Pallas kernel (repro.kernels.fused_update)
+implements the same SGD update on a flat buffer but is not on the train
+step.
 """
 from __future__ import annotations
 

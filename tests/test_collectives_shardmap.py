@@ -5,8 +5,6 @@ import os
 import subprocess
 import sys
 
-import pytest
-
 SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
@@ -81,11 +79,6 @@ print("SHARDMAP_OK")
 """
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="seed failure (ISSUE 2): container jax predates "
-           "jax.sharding.AxisType / jax.shard_map, so the 8-device "
-           "subprocess dies at import; passes on jax >= 0.4.35")
 def test_shardmap_allreduce_8dev():
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=8",

@@ -12,14 +12,6 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 # Subprocess dry-runs take minutes: keep them out of the fast CI lane.
 pytestmark = pytest.mark.slow
 
-# Seed failures tracked in ISSUE 2: the container's jax predates
-# jax.sharding.AxisType, so every dryrun subprocess dies at import.  xfail
-# (non-strict) keeps CI green without hiding a fix or a new regression.
-_SEED_XFAIL = pytest.mark.xfail(
-    strict=False,
-    reason="seed failure (ISSUE 2): container jax predates "
-           "jax.sharding.AxisType; dryrun subprocess fails at import")
-
 CASES = [
     ("qwen2.5-3b", "train_4k", "single"),
     ("qwen3-moe-30b-a3b", "prefill_32k", "single"),
@@ -29,7 +21,6 @@ CASES = [
 ]
 
 
-@_SEED_XFAIL
 @pytest.mark.parametrize("arch,shape,mesh", CASES)
 def test_dryrun_tiny(arch, shape, mesh):
     env = dict(os.environ, REPRO_DRYRUN_DEVICES="8", PYTHONPATH=SRC)
@@ -41,7 +32,6 @@ def test_dryrun_tiny(arch, shape, mesh):
     assert "OK " in r.stdout
 
 
-@_SEED_XFAIL
 def test_dryrun_records_roofline_terms(tmp_path):
     env = dict(os.environ, REPRO_DRYRUN_DEVICES="8", PYTHONPATH=SRC)
     r = subprocess.run(

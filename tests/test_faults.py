@@ -432,7 +432,7 @@ class _TinyModel:
     def init(self, key):
         return {"w": jnp.zeros((3,))}
 
-    def loss(self, params, batch):
+    def loss(self, params, batch, sh=None):
         pred = batch["x"] @ params["w"]
         return jnp.mean((pred - batch["y"]) ** 2)
 

@@ -1,5 +1,6 @@
-"""Per-kernel validation: shape/dtype sweeps against the ref.py oracles
-(interpret=True executes the Pallas body on CPU)."""
+"""Per-kernel validation: shape/dtype sweeps against the ref.py oracles.
+Every call passes interpret=True, which runs the Pallas body on the CPU;
+tests/test_tpu_compile.py compiles the same kernels for a TPU."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -24,7 +25,7 @@ def test_swa_attention_sweep(bh, s, d, window, causal, dtype):
     k = jax.random.normal(k2, (bh, s, d), dtype)
     v = jax.random.normal(k3, (bh, s, d), dtype)
     got = ops.swa_attention(q, k, v, causal=causal, window=window,
-                            block_q=64, block_k=64)
+                            block_q=64, block_k=64, interpret=True)
     want = ref.swa_attention_ref(q, k, v, causal=causal, window=window)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -38,7 +39,8 @@ def test_swa_attention_block_shape_invariance():
     q = jax.random.normal(k1, (2, 256, 64), jnp.float32)
     k = jax.random.normal(k2, (2, 256, 64), jnp.float32)
     v = jax.random.normal(k3, (2, 256, 64), jnp.float32)
-    outs = [ops.swa_attention(q, k, v, window=100, block_q=bq, block_k=bk)
+    outs = [ops.swa_attention(q, k, v, window=100, block_q=bq, block_k=bk,
+                              interpret=True)
             for bq, bk in [(32, 32), (64, 128), (128, 64), (256, 256)]]
     for o in outs[1:]:
         np.testing.assert_allclose(np.asarray(o), np.asarray(outs[0]),
@@ -51,9 +53,11 @@ def test_swa_window_blocks_are_skipped_semantically():
     q = jax.random.normal(k1, (1, 256, 32), jnp.float32)
     k = jax.random.normal(k2, (1, 256, 32), jnp.float32)
     v = jax.random.normal(k3, (1, 256, 32), jnp.float32)
-    base = ops.swa_attention(q, k, v, window=16, block_q=64, block_k=64)
+    base = ops.swa_attention(q, k, v, window=16, block_q=64, block_k=64,
+                             interpret=True)
     k2_, v2_ = k.at[:, :128].set(99.0), v.at[:, :128].set(-99.0)
-    pert = ops.swa_attention(q, k2_, v2_, window=16, block_q=64, block_k=64)
+    pert = ops.swa_attention(q, k2_, v2_, window=16, block_q=64, block_k=64,
+                             interpret=True)
     np.testing.assert_allclose(np.asarray(pert[:, 192:]),
                                np.asarray(base[:, 192:]), rtol=1e-6)
 
@@ -66,7 +70,8 @@ def test_fused_update_property(n, lr, momentum):
     p = jax.random.normal(k1, (n,), jnp.float32)
     g = jax.random.normal(k2, (n,), jnp.float32)
     mu = jax.random.normal(k3, (n,), jnp.float32)
-    got = ops.fused_sgd_update(p, g, mu, lr, momentum=momentum, block=512)
+    got = ops.fused_sgd_update(p, g, mu, lr, momentum=momentum, block=512,
+                               interpret=True)
     want = ref.fused_sgd_update_ref(p, g, mu, lr, momentum=momentum)
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
                                rtol=1e-5, atol=1e-5)
@@ -82,7 +87,8 @@ def test_fused_update_shapes(n, block, nesterov):
     p = jax.random.normal(k1, (n,), jnp.float32)
     g = jax.random.normal(k2, (n,), jnp.float32)
     mu = jax.random.normal(k3, (n,), jnp.float32)
-    got = ops.fused_sgd_update(p, g, mu, 0.1, nesterov=nesterov, block=block)
+    got = ops.fused_sgd_update(p, g, mu, 0.1, nesterov=nesterov, block=block,
+                               interpret=True)
     want = ref.fused_sgd_update_ref(p, g, mu, 0.1, nesterov=nesterov)
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
                                rtol=1e-5, atol=1e-5)
@@ -104,7 +110,7 @@ def test_fused_update_equals_sgd_optimizer_step():
     flat_mu = jnp.zeros_like(flat_p)
     got_p, got_mu = ops.fused_sgd_update(flat_p, flat_g, flat_mu, 0.05,
                                          momentum=0.9, weight_decay=1e-4,
-                                         block=32)
+                                         block=32, interpret=True)
     want_p = jnp.concatenate([new_params["a"], new_params["b"]])
     np.testing.assert_allclose(np.asarray(got_p), np.asarray(want_p),
                                rtol=1e-5, atol=1e-6)
@@ -118,7 +124,7 @@ def test_rmsnorm_sweep(shape, block_rows, dtype):
     k1, k2 = jax.random.split(jax.random.PRNGKey(sum(shape)))
     x = jax.random.normal(k1, shape, dtype)
     w = jax.random.normal(k2, (shape[-1],), jnp.float32) * 0.1
-    got = ops.rmsnorm(x, w, block_rows=block_rows)
+    got = ops.rmsnorm(x, w, block_rows=block_rows, interpret=True)
     want = ref.rmsnorm_ref(x, w)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -132,6 +138,6 @@ def test_rmsnorm_matches_model_layer():
     k1, k2 = jax.random.split(jax.random.PRNGKey(5))
     x = jax.random.normal(k1, (3, 17, 256), jnp.float32)
     w = jax.random.normal(k2, (256,), jnp.float32) * 0.1
-    np.testing.assert_allclose(np.asarray(ops.rmsnorm(x, w)),
+    np.testing.assert_allclose(np.asarray(ops.rmsnorm(x, w, interpret=True)),
                                np.asarray(layer_rmsnorm(x, w)),
                                rtol=1e-5, atol=1e-5)

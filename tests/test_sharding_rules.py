@@ -4,7 +4,6 @@ import os
 import subprocess
 import sys
 
-import pytest
 from _hypothesis_compat import given, settings, strategies as st
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -16,11 +15,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 import jax
 import numpy as np
 
-try:
-    from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
-except ImportError:  # older jax: no AbstractMesh/AxisType
-    pytest.skip("jax.sharding lacks AbstractMesh/AxisType in this jax",
-                allow_module_level=True)
+from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
 
 from repro.sharding.rules import default_rules
 
