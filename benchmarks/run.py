@@ -1,11 +1,12 @@
-"""Benchmark harness — one module per paper table + perf benches.
+"""Host benchmarks of the scheduler: the paper's Table 3 and the simulator's
+parity and speed gates.  The runtime's speed is measured on the chip by
+``benchmarks/chip/run.py``.
 
 Prints ``name,us_per_call,derived`` CSV lines; ``--json out.json``
 additionally writes the same rows as machine-readable JSON
 (``{name: {us_per_call, derived}}``).
 
-  PYTHONPATH=src python -m benchmarks.run                    # all tables
-  PYTHONPATH=src python -m benchmarks.run table3             # one table
+  PYTHONPATH=src python -m benchmarks.run                    # table3
   PYTHONPATH=src python -m benchmarks.run scheduler --json out.json
 """
 from __future__ import annotations
@@ -25,14 +26,9 @@ def main(argv: list[str] | None = None) -> None:
         except IndexError:
             raise SystemExit("--json requires an output path")
         del argv[i:i + 2]
-    want = argv or ["table1", "table2", "table3", "roofline"]
-    from repro.launch.compile_cache import use_compile_cache
-    use_compile_cache()
-    from benchmarks import (bench_scheduler, roofline, table1_profiling,
-                            table2_stop_restart, table3_scheduler_sim)
-    mods = {"table1": table1_profiling, "table2": table2_stop_restart,
-            "table3": table3_scheduler_sim, "roofline": roofline,
-            "scheduler": bench_scheduler}
+    want = argv or ["table3"]
+    from benchmarks import bench_scheduler, table3_scheduler_sim
+    mods = {"table3": table3_scheduler_sim, "scheduler": bench_scheduler}
     unknown = [n for n in want if n not in mods]
     if unknown:
         raise SystemExit(f"unknown benchmark(s) {unknown}; "

@@ -3,8 +3,9 @@
 Elasticity is the point (paper §6): params and optimizer state are
 data-parallel-replicated, so a checkpoint written at w workers restores
 bit-identically at any w' — the restart only changes the mesh and the LR
-(eq. 7).  Save/restore round-trip times are measured by
-benchmarks/table2_stop_restart.py.
+(eq. 7).  ``save`` and ``restore`` return their host seconds; the elastic
+trainer also marks each call with its ``elastic.save`` / ``elastic.restore``
+span (``core/elastic.py``).
 """
 from __future__ import annotations
 

@@ -8,11 +8,23 @@ batch size, exactly as the paper describes.  A segment at w workers runs on a
 data mesh of w devices when that many exist (batch sharded, state
 replicated, gradients exchanged by ``grad_exchange``); otherwise it runs the
 global batch on one device, and ``SegmentRecord.devices`` says which
-happened.  Stop and restart costs are measured, not assumed —
-benchmarks/table2_stop_restart.py reports them.
+happened.
+
+Stop and restart costs are measured, not assumed.  ``train_segment`` marks
+each part of a segment with a span named ``elastic.<part>`` (``SPANS``):
+a ``jax.profiler`` annotation on the profiler's clock, and a timer of the
+same name in ``ElasticTrainer.registry`` (a ``core.telemetry.Registry``),
+beside the counters ``elastic.steps``, ``elastic.samples`` and
+``elastic.step_builds``.  The set-up of a segment (``init_state``,
+``restore``, ``place``, ``first_step``) is what a restart costs; ``step``
+(a ``StepTraceAnnotation`` numbered by the global step) holds ``input``,
+``h2d``, ``dispatch`` and, at log steps, ``loss_sync``.  In a segment's
+first step, ``first_step`` holds its ``input``, ``h2d`` and ``dispatch``
+and lasts until that step is ready.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 
@@ -20,10 +32,17 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint.store import CheckpointStore
+from repro.core import telemetry
 from repro.engine.steps import make_data_parallel_step
 from repro.launch.mesh import make_data_mesh
 from repro.optim.optimizers import Optimizer
 from repro.optim.schedule import rescale_lr
+
+
+# The parts of a segment, each a span ``elastic.<part>`` (module docstring).
+SPANS = ("segment", "init_state", "restore", "place", "first_step", "step",
+         "input", "h2d", "dispatch", "loss_sync", "drain", "save")
+_NO_SPAN = contextlib.nullcontext()
 
 
 @dataclasses.dataclass
@@ -59,6 +78,7 @@ class ElasticTrainer:
         self.decay_factor = decay_factor
         self.dataset = dataset_size or getattr(data, "size", 50_000)
         self.grad_exchange = grad_exchange
+        self.registry = telemetry.Registry()
         self._steps: dict[int, tuple] = {}
 
     # ------------------------------------------------------------ state ----
@@ -84,6 +104,7 @@ class ElasticTrainer:
         one."""
         n = w if jax.device_count() >= w else 1
         if n not in self._steps:
+            self.registry.counter("elastic.step_builds").inc()
             mesh = make_data_mesh(n)
             self._steps[n] = make_data_parallel_step(
                 self.model, self.opt, mesh, self.grad_exchange)
@@ -92,47 +113,66 @@ class ElasticTrainer:
     # ---------------------------------------------------------- segments ---
     def train_segment(self, w: int, n_steps: int, *, resume: bool = True,
                       log_every: int = 10) -> SegmentRecord:
-        restore_s = 0.0
-        if resume and self.ckpt.latest_step() is not None:
-            template = self.fresh_state()
-            state, meta, restore_s = self.ckpt.restore(template)
-        else:
-            state = self.fresh_state()
-        step, rep, data_sharding = self.step_for(w)
-        devices = len(rep.device_set)
+        sp = {p: self.registry.span("elastic." + p, step=p == "step")
+              for p in SPANS}
+        steps_done = self.registry.counter("elastic.steps")
+        samples_done = self.registry.counter("elastic.samples")
+        with sp["segment"](w=w, steps=n_steps):
+            restore_s = 0.0
+            if resume and self.ckpt.latest_step() is not None:
+                with sp["init_state"]:
+                    template = self.fresh_state()
+                with sp["restore"]:
+                    state, meta, restore_s = self.ckpt.restore(template)
+            else:
+                with sp["init_state"]:
+                    state = self.fresh_state()
+            step, rep, data_sharding = self.step_for(w)
+            devices = len(rep.device_set)
 
-        global_batch = self.m * w
-        epochs_per_step = global_batch / self.dataset
-        losses = []
-        t0 = time.perf_counter()
-        step0 = int(state["step"])
-        epoch = float(state["epoch"])
-        lr0 = self._lr(w, epoch)
-        train_state = jax.device_put(
-            {"params": state["params"], "opt": state["opt"]}, rep)
-        first_s = 0.0
-        for i in range(n_steps):
-            gstep = step0 + i
-            batch = jax.device_put(self.data.batch(gstep, global_batch),
-                                   data_sharding)
-            train_state, loss = step(train_state, batch,
-                                     self._lr(w, epoch))
-            if i == 0:
-                jax.block_until_ready((train_state, loss))
-                first_s = time.perf_counter() - t0
-            epoch += epochs_per_step
-            if i % log_every == 0 or i == n_steps - 1:
-                losses.append((gstep, epoch, float(loss)))
-        jax.block_until_ready(train_state)
-        seconds = time.perf_counter() - t0
-        step_s = ((seconds - first_s) / (n_steps - 1) if n_steps > 1
-                  else float("nan"))
+            global_batch = self.m * w
+            epochs_per_step = global_batch / self.dataset
+            losses = []
+            t0 = time.perf_counter()
+            step0 = int(state["step"])
+            epoch = float(state["epoch"])
+            lr0 = self._lr(w, epoch)
+            with sp["place"]:
+                train_state = jax.device_put(
+                    {"params": state["params"], "opt": state["opt"]}, rep)
+            first_s = 0.0
+            for i in range(n_steps):
+                gstep = step0 + i
+                with sp["step"](step_num=gstep):
+                    with sp["first_step"] if i == 0 else _NO_SPAN:
+                        with sp["input"]:
+                            batch = self.data.batch(gstep, global_batch)
+                        with sp["h2d"]:
+                            batch = jax.device_put(batch, data_sharding)
+                        with sp["dispatch"]:
+                            train_state, loss = step(train_state, batch,
+                                                     self._lr(w, epoch))
+                        if i == 0:
+                            jax.block_until_ready((train_state, loss))
+                            first_s = time.perf_counter() - t0
+                    epoch += epochs_per_step
+                    if i % log_every == 0 or i == n_steps - 1:
+                        with sp["loss_sync"]:
+                            losses.append((gstep, epoch, float(loss)))
+                steps_done.inc()
+                samples_done.inc(global_batch)
+            with sp["drain"]:
+                jax.block_until_ready(train_state)
+            seconds = time.perf_counter() - t0
+            step_s = ((seconds - first_s) / (n_steps - 1) if n_steps > 1
+                      else float("nan"))
 
-        state = {**train_state,
-                 "step": jnp.asarray(step0 + n_steps, jnp.int32),
-                 "epoch": jnp.asarray(epoch, jnp.float32)}
-        save_s = self.ckpt.save(step0 + n_steps, state,
-                                meta={"w": w, "epoch": epoch})
+            state = {**train_state,
+                     "step": jnp.asarray(step0 + n_steps, jnp.int32),
+                     "epoch": jnp.asarray(epoch, jnp.float32)}
+            with sp["save"]:
+                save_s = self.ckpt.save(step0 + n_steps, state,
+                                        meta={"w": w, "epoch": epoch})
         return SegmentRecord(w=w, devices=devices, steps=n_steps,
                              epochs=epoch, lr=lr0, losses=losses,
                              seconds=seconds, first_step_seconds=first_s,
