@@ -21,12 +21,27 @@ beside the counters ``elastic.steps``, ``elastic.samples`` and
 ``h2d``, ``dispatch`` and, at log steps, ``loss_sync``.  In a segment's
 first step, ``first_step`` holds its ``input``, ``h2d`` and ``dispatch``
 and lasts until that step is ready.
+
+The data source.  ``data.batch(step, global_batch)`` must be a pure
+function of its arguments.  A segment asks for its first step's batch
+inline; once that step is ready, one producer thread makes the batches of
+the later steps ahead of the loop, at most ``PREFETCH`` of them, so host
+input overlaps the loop's dispatch.  ``batch`` is thus called once per
+step, in step order, never past the segment, and after the first step from
+a thread that is not the caller's.  ``input`` then times the wait for the
+prefetched batch (the input the loop is exposed to), the producer's span
+``prefetch`` times each call of ``batch``, and the counter
+``elastic.prefetch_ready`` counts the steps whose batch was made before the
+loop asked for it.  An error that ``batch`` raises for step k surfaces
+when the loop reaches step k; the producer stops with the segment.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import time
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
@@ -41,8 +56,55 @@ from repro.optim.schedule import rescale_lr
 
 # The parts of a segment, each a span ``elastic.<part>`` (module docstring).
 SPANS = ("segment", "init_state", "restore", "place", "first_step", "step",
-         "input", "h2d", "dispatch", "loss_sync", "drain", "save")
+         "input", "h2d", "dispatch", "loss_sync", "drain", "save",
+         "prefetch")
 _NO_SPAN = contextlib.nullcontext()
+# Batches the producer makes ahead of the loop.  With one, a batch that
+# takes longer than the loop's step stalls the loop, and the producer can
+# build no lead to absorb it: on a TPU v5e host, ResNet-110 at 128 images a
+# step trained 24% fewer images a second with one than with two.
+PREFETCH = 2
+
+
+class _Producer:
+    """The batches of steps ``first``, ``first + 1``, ... ``end - 1``, made
+    in order on one thread, at most ``PREFETCH`` ahead of the loop."""
+
+    def __init__(self, data, global_batch: int, first: int, end: int,
+                 span, ready):
+        self._data = data
+        self._rows = global_batch
+        self._span = span
+        self._ready = ready
+        self._next = first
+        self._end = end
+        self._pending = collections.deque()
+        self._pool = ThreadPoolExecutor(
+            max_workers=1, thread_name_prefix="elastic.prefetch")
+        for _ in range(PREFETCH):
+            self._submit()
+
+    def _make(self, step: int) -> dict:
+        with self._span:
+            return self._data.batch(step, self._rows)
+
+    def _submit(self) -> None:
+        if self._next < self._end:
+            self._pending.append(self._pool.submit(self._make, self._next))
+            self._next += 1
+
+    def get(self) -> dict:
+        """The next step's batch; raises what ``batch`` raised for it."""
+        fut = self._pending.popleft()
+        if fut.done():
+            self._ready.inc()
+        batch = fut.result()
+        self._submit()
+        return batch
+
+    def close(self) -> None:
+        """Cancel the batches not begun and join the thread."""
+        self._pool.shutdown(wait=True, cancel_futures=True)
 
 
 @dataclasses.dataclass
@@ -117,6 +179,7 @@ class ElasticTrainer:
               for p in SPANS}
         steps_done = self.registry.counter("elastic.steps")
         samples_done = self.registry.counter("elastic.samples")
+        ready = self.registry.counter("elastic.prefetch_ready")
         with sp["segment"](w=w, steps=n_steps):
             restore_s = 0.0
             if resume and self.ckpt.latest_step() is not None:
@@ -141,26 +204,36 @@ class ElasticTrainer:
                 train_state = jax.device_put(
                     {"params": state["params"], "opt": state["opt"]}, rep)
             first_s = 0.0
-            for i in range(n_steps):
-                gstep = step0 + i
-                with sp["step"](step_num=gstep):
-                    with sp["first_step"] if i == 0 else _NO_SPAN:
-                        with sp["input"]:
-                            batch = self.data.batch(gstep, global_batch)
-                        with sp["h2d"]:
-                            batch = jax.device_put(batch, data_sharding)
-                        with sp["dispatch"]:
-                            train_state, loss = step(train_state, batch,
-                                                     self._lr(w, epoch))
-                        if i == 0:
-                            jax.block_until_ready((train_state, loss))
-                            first_s = time.perf_counter() - t0
-                    epoch += epochs_per_step
-                    if i % log_every == 0 or i == n_steps - 1:
-                        with sp["loss_sync"]:
-                            losses.append((gstep, epoch, float(loss)))
-                steps_done.inc()
-                samples_done.inc(global_batch)
+            producer = None
+            try:
+                for i in range(n_steps):
+                    gstep = step0 + i
+                    with sp["step"](step_num=gstep):
+                        with sp["first_step"] if i == 0 else _NO_SPAN:
+                            with sp["input"]:
+                                batch = (producer.get() if i else
+                                         self.data.batch(gstep, global_batch))
+                            with sp["h2d"]:
+                                batch = jax.device_put(batch, data_sharding)
+                            with sp["dispatch"]:
+                                train_state, loss = step(train_state, batch,
+                                                         self._lr(w, epoch))
+                            if i == 0:
+                                jax.block_until_ready((train_state, loss))
+                                first_s = time.perf_counter() - t0
+                        if i == 0 and n_steps > 1:
+                            producer = _Producer(
+                                self.data, global_batch, step0 + 1,
+                                step0 + n_steps, sp["prefetch"], ready)
+                        epoch += epochs_per_step
+                        if i % log_every == 0 or i == n_steps - 1:
+                            with sp["loss_sync"]:
+                                losses.append((gstep, epoch, float(loss)))
+                    steps_done.inc()
+                    samples_done.inc(global_batch)
+            finally:
+                if producer is not None:
+                    producer.close()
             with sp["drain"]:
                 jax.block_until_ready(train_state)
             seconds = time.perf_counter() - t0

@@ -1,7 +1,8 @@
 """The elastic loop's spans and counters (``core/elastic.py``): each part of a
 segment is a ``jax.profiler`` annotation named ``elastic.<part>`` and a
-timer of the same name in the trainer's ``telemetry.Registry``; the
-profiler leaves the computation bit for bit as it is."""
+timer of the same name in the trainer's ``telemetry.Registry``, the
+producer's ``elastic.prefetch`` among them; the profiler leaves the
+computation bit for bit as it is."""
 import glob
 import os
 
@@ -93,6 +94,19 @@ def test_loss_syncs_and_segment_spans(runs):
     assert all(seg[0] <= s and e <= seg[1] for s, e, _, _ in runs["spans"])
 
 
+def test_prefetch_spans_lie_in_the_segment_after_its_first_step(runs):
+    spans = runs["spans"]
+    (seg,) = [s for s in spans if s[2] == "elastic.segment"]
+    (first,) = [s for s in spans if s[2] == "elastic.first_step"]
+    prefetch = [s for s in spans if s[2] == "elastic.prefetch"]
+    # One for each step after the first, none past the segment's last.
+    assert len(prefetch) == STEPS - 1
+    for s, e, _, _ in prefetch:
+        assert seg[0] <= s and e <= seg[1]
+        assert not (first[0] <= s and e <= first[1])
+        assert s >= first[1]
+
+
 def test_first_step_nests_in_the_segments_first_step(runs):
     spans = runs["spans"]
     (first,) = [s for s in spans if s[2] == "elastic.first_step"]
@@ -108,8 +122,14 @@ def test_registry_counts_steps_samples_and_log_steps(runs):
     reg = tr.registry
     n = 1 + STEPS
     # The second segment at the same w reuses the step: one build.
-    assert reg.counters() == {"elastic.samples": n * M,
-                              "elastic.step_builds": 1, "elastic.steps": n}
+    counters = reg.counters()
+    # Steps whose prefetched batch was made when the loop asked for it: at
+    # most one per step after each segment's first.
+    assert 0 <= counters["elastic.prefetch_ready"] <= STEPS - 1
+    assert counters == {"elastic.samples": n * M,
+                        "elastic.step_builds": 1, "elastic.steps": n,
+                        "elastic.prefetch_ready":
+                            counters["elastic.prefetch_ready"]}
     timers = reg.timers()
     counts = {k: v["count"] for k, v in timers.items()}
     assert counts == {
@@ -117,7 +137,7 @@ def test_registry_counts_steps_samples_and_log_steps(runs):
         "elastic.place": 2, "elastic.first_step": 2, "elastic.step": n,
         "elastic.input": n, "elastic.h2d": n, "elastic.dispatch": n,
         "elastic.loss_sync": 1 + len(rec.losses), "elastic.drain": 2,
-        "elastic.save": 2}
+        "elastic.save": 2, "elastic.prefetch": STEPS - 1}
     assert all(v["total_s"] > 0 for v in timers.values())
     # A part takes no longer than the whole that holds it.
     assert timers["elastic.step"]["total_s"] <= \
@@ -140,8 +160,13 @@ def test_shared_registry_accumulates_across_trainers(tmp_path):
         tr = trainer(str(tmp_path / "ckpt"))
         tr.registry = reg
         tr.train_segment(1, 2, resume=bool(i), log_every=LOG_EVERY)
-    assert reg.counters() == {"elastic.samples": 4 * M,
-                              "elastic.step_builds": 2, "elastic.steps": 4}
+    counters = reg.counters()
+    assert 0 <= counters["elastic.prefetch_ready"] <= 2
+    assert counters == {"elastic.samples": 4 * M,
+                        "elastic.step_builds": 2, "elastic.steps": 4,
+                        "elastic.prefetch_ready":
+                            counters["elastic.prefetch_ready"]}
+    assert reg.timers()["elastic.prefetch"]["count"] == 2
     assert reg.timers()["elastic.segment"]["count"] == 2
     assert reg.timers()["elastic.restore"]["count"] == 1
 
