@@ -14,6 +14,7 @@ _ARCH_MODULES = {
     "dbrx-132b": "repro.configs.dbrx_132b",
     "whisper-base": "repro.configs.whisper_base",
     "qwen2.5-14b": "repro.configs.qwen25_14b",
+    "deepseek-v2-lite": "repro.configs.deepseek_v2_lite",
 }
 
 ARCH_IDS = tuple(_ARCH_MODULES)

@@ -14,6 +14,17 @@ Family = Literal["dense", "moe", "ssm", "hybrid", "vlm", "audio"]
 
 
 @dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rotary scaling (DeepSeek-V2's ``rope_scaling`` of type yarn)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float
+    beta_slow: float
+    mscale: float
+    mscale_all_dim: float
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: Family
@@ -37,6 +48,15 @@ class ModelConfig:
     rope_theta: float = 10_000.0
     mrope: bool = False                  # Qwen2-VL multimodal 3D RoPE
     sliding_window: int | None = None    # native SWA (h2o-danube)
+    # latent attention (MLA, DeepSeek-V2; on when kv_lora_rank > 0): keys
+    # and values come up from a kv_lora_rank-wide latent, and one
+    # qk_rope_head_dim-wide rotary key is shared by every head; MLA
+    # requires rope_yarn
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_yarn: Yarn | None = None
     # long_500k fallback window for otherwise full-attention archs:
     long_context_window: int = 4096
 
@@ -49,6 +69,16 @@ class ModelConfig:
     top_k: int = 0
     capacity_factor: float = 1.25
     moe_every: int = 1                   # apply MoE every k-th layer
+    # Drop-free MoE (models/moe.py ``moe_dropless``): every token is routed
+    # over all n_experts; this layer holds experts_held of them (0: all),
+    # from expert_offset on, and computes their part of the result.
+    moe_dropless: bool = False
+    experts_held: int = 0
+    expert_offset: int = 0
+    n_shared_experts: int = 0            # always-on experts, d_ff wide each
+    aux_loss_coef: float = 0.01
+    first_dense_layers: int = 0          # dense layers ahead of the MoE ones
+    dense_d_ff: int = 0                  # their FFN width
 
     # SSM (Mamba2 / Jamba mamba layers)
     ssm_state: int = 0
@@ -80,6 +110,10 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
 
     @property
     def attention_free(self) -> bool:

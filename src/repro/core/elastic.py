@@ -22,6 +22,15 @@ beside the counters ``elastic.steps``, ``elastic.samples`` and
 first step, ``first_step`` holds its ``input``, ``h2d`` and ``dispatch``
 and lasts until that step is ready.
 
+The step donates the state it is given (``make_data_parallel_step``): the
+loop rebinds the state to the step's result and never reads one it has
+passed in.  ``dispatch`` also holds the wait that keeps the loop at most
+``IN_FLIGHT`` steps ahead of the device.  A model with ``step_stats`` (the drop-free MoE) has its step
+return its held experts' loads as well; at log steps the loop reads them
+in the same transfer as the loss and adds them to the counter
+``moe.assignments_held`` and the peak ``moe.busiest_expert_ratio`` (a
+layer's busiest held expert over its mean load, the highest seen).
+
 The data source.  ``data.batch(step, global_batch)`` must be a pure
 function of its arguments.  A segment asks for its first step's batch
 inline; once that step is ready, one producer thread makes the batches of
@@ -45,6 +54,7 @@ from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.checkpoint.store import CheckpointStore
 from repro.core import telemetry
@@ -64,6 +74,12 @@ _NO_SPAN = contextlib.nullcontext()
 # build no lead to absorb it: on a TPU v5e host, ResNet-110 at 128 images a
 # step trained 24% fewer images a second with one than with two.
 PREFETCH = 2
+# Steps the loop dispatches ahead of the device: after dispatching a step
+# it waits, inside ``dispatch``, until the step IN_FLIGHT back is done.
+# The step donates its state, so nothing else holds the loop back, and a
+# loop that ran ahead of a device-bound step would queue the whole segment
+# at once; two keep the next step queued behind the running one.
+IN_FLIGHT = 2
 
 
 class _Producer:
@@ -105,6 +121,16 @@ class _Producer:
     def close(self) -> None:
         """Cancel the batches not begun and join the thread."""
         self._pool.shutdown(wait=True, cancel_futures=True)
+
+
+def _record_stats(registry, stats: dict) -> None:
+    """Add one log step's held-expert loads ([layers, held]) to the MoE
+    counters."""
+    loads = np.asarray(stats["moe_assignments_held"], np.float64)
+    registry.counter("moe.assignments_held").inc(int(loads.sum()))
+    mean = np.maximum(loads.mean(axis=-1), 1e-9)
+    registry.peak("moe.busiest_expert_ratio").see(
+        float(np.max(loads.max(axis=-1) / mean)))
 
 
 @dataclasses.dataclass
@@ -184,7 +210,8 @@ class ElasticTrainer:
             restore_s = 0.0
             if resume and self.ckpt.latest_step() is not None:
                 with sp["init_state"]:
-                    template = self.fresh_state()
+                    # The store reads only the template's tree of names.
+                    template = jax.eval_shape(self.fresh_state)
                 with sp["restore"]:
                     state, meta, restore_s = self.ckpt.restore(template)
             else:
@@ -203,8 +230,12 @@ class ElasticTrainer:
             with sp["place"]:
                 train_state = jax.device_put(
                     {"params": state["params"], "opt": state["opt"]}, rep)
+            # The placed state is donated to the first step; a state it was
+            # copied from must not stay on the device beside it.
+            del state
             first_s = 0.0
             producer = None
+            in_flight = collections.deque()
             try:
                 for i in range(n_steps):
                     gstep = step0 + i
@@ -216,8 +247,12 @@ class ElasticTrainer:
                             with sp["h2d"]:
                                 batch = jax.device_put(batch, data_sharding)
                             with sp["dispatch"]:
-                                train_state, loss = step(train_state, batch,
-                                                         self._lr(w, epoch))
+                                train_state, loss, *stats = step(
+                                    train_state, batch, self._lr(w, epoch))
+                                in_flight.append(loss)
+                                if len(in_flight) > IN_FLIGHT:
+                                    jax.block_until_ready(
+                                        in_flight.popleft())
                             if i == 0:
                                 jax.block_until_ready((train_state, loss))
                                 first_s = time.perf_counter() - t0
@@ -228,7 +263,10 @@ class ElasticTrainer:
                         epoch += epochs_per_step
                         if i % log_every == 0 or i == n_steps - 1:
                             with sp["loss_sync"]:
+                                loss, stats = jax.device_get((loss, stats))
                                 losses.append((gstep, epoch, float(loss)))
+                                for st in stats:
+                                    _record_stats(self.registry, st)
                     steps_done.inc()
                     samples_done.inc(global_batch)
             finally:

@@ -11,7 +11,8 @@ parts:
    and a streaming Chrome trace-event writer (:class:`ChromeTraceSink`).
 
 2. **Counter/timer registry** — :class:`Registry` hands out
-   :class:`Counter`/:class:`Timer` objects resolved once at engine setup.
+   :class:`Counter`/:class:`Timer`/:class:`Peak` objects resolved once at
+   engine setup.
    The disabled path is a module-level no-op singleton
    (:data:`NULL_RECORDER`), so hot loops pay a single attribute check
    (``rec.on``) when telemetry is off.  :meth:`Registry.span` gives a
@@ -172,6 +173,22 @@ class _NullTimer:
         pass
 
 
+class Peak:
+    """The highest value seen under a name."""
+
+    __slots__ = ("name", "value")
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+        self.value = float("-inf")
+
+    def see(self, v: float) -> None:
+        self.value = max(self.value, v)
+
+    def __repr__(self) -> str:  # pragma: no cover - debug aid
+        return f"Peak({self.name}={self.value})"
+
+
 NULL_COUNTER = _NullCounter()
 NULL_TIMER = _NullTimer()
 
@@ -183,11 +200,12 @@ class Registry:
     ``c.inc()`` in the hot loop — no dict lookup per increment.
     """
 
-    __slots__ = ("_counters", "_timers")
+    __slots__ = ("_counters", "_timers", "_peaks")
 
     def __init__(self) -> None:
         self._counters: dict[str, Counter] = {}
         self._timers: dict[str, Timer] = {}
+        self._peaks: dict[str, Peak] = {}
 
     def counter(self, name: str) -> Counter:
         c = self._counters.get(name)
@@ -201,6 +219,12 @@ class Registry:
             t = self._timers[name] = Timer(name)
         return t
 
+    def peak(self, name: str) -> Peak:
+        p = self._peaks.get(name)
+        if p is None:
+            p = self._peaks[name] = Peak(name)
+        return p
+
     def counters(self) -> dict[str, int]:
         return {k: v.n for k, v in sorted(self._counters.items())}
 
@@ -209,6 +233,9 @@ class Registry:
             k: {"total_s": v.total_s, "count": v.count}
             for k, v in sorted(self._timers.items())
         }
+
+    def peaks(self) -> dict[str, float]:
+        return {k: v.value for k, v in sorted(self._peaks.items())}
 
     def span(self, name: str, *, step: bool = False) -> Span:
         """A :class:`Span` that adds to ``timer(name)``; with ``step`` it

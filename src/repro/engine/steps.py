@@ -11,10 +11,24 @@ from repro.models.layers import Sharder, NO_SHARD
 from repro.optim.optimizers import Optimizer
 
 
+def _reports_stats(model) -> bool:
+    """Whether the model's step returns its ``loss_and_stats`` counts."""
+    return getattr(model, "step_stats", False)
+
+
+def step_outputs(model, state, scalar) -> tuple:
+    """The train step's outputs in the form ``make_train_step`` gives them,
+    with ``state`` for the state and ``scalar`` for the loss and the stats
+    (a sharding or a spec for each, say)."""
+    return (state, scalar) + ((scalar,) if _reports_stats(model) else ())
+
+
 def make_train_step(model, optimizer: Optimizer, sh: Sharder = NO_SHARD,
                     grad_exchange: str | None = None, axis: str = "data",
                     microbatches: int = 1):
-    """(state {params, opt}, batch, lr) -> (state, loss).
+    """(state {params, opt}, batch, lr) -> (state, loss), or (state, loss,
+    stats) for a model whose ``step_stats`` is true: the dict of counts its
+    ``loss_and_stats`` reports (summed over microbatches and workers).
 
     grad_exchange: None => implicit GSPMD reduction (production path);
     "ring"/"doubling_halving" are only valid inside shard_map (the
@@ -26,13 +40,22 @@ def make_train_step(model, optimizer: Optimizer, sh: Sharder = NO_SHARD,
     training; EXPERIMENTS.md §Perf).
     """
 
+    with_stats = _reports_stats(model)
+
     def grads_of(params, batch):
-        return jax.value_and_grad(lambda p: model.loss(p, batch, sh))(params)
+        """-> ((loss, stats), grads)."""
+        if with_stats:
+            return jax.value_and_grad(
+                lambda p: model.loss_and_stats(p, batch, sh),
+                has_aux=True)(params)
+        loss, grads = jax.value_and_grad(
+            lambda p: model.loss(p, batch, sh))(params)
+        return (loss, {}), grads
 
     def train_step(state, batch, lr):
         params = state["params"]
         if microbatches == 1:
-            loss, grads = grads_of(params, batch)
+            (loss, stats), grads = grads_of(params, batch)
         else:
             k = microbatches
             mb = jax.tree_util.tree_map(
@@ -43,23 +66,28 @@ def make_train_step(model, optimizer: Optimizer, sh: Sharder = NO_SHARD,
 
             def body(carry, mbatch):
                 acc, lsum = carry
-                loss_i, g_i = grads_of(params, mbatch)
+                (loss_i, stats_i), g_i = grads_of(params, mbatch)
                 acc = jax.tree_util.tree_map(
                     lambda a, g: a + g.astype(jnp.float32), acc, g_i)
-                return (acc, lsum + loss_i), None
+                return (acc, lsum + loss_i), stats_i
 
-            (grads, lsum), _ = jax.lax.scan(body, (zeros, 0.0), mb)
+            (grads, lsum), stats = jax.lax.scan(body, (zeros, 0.0), mb)
             grads = jax.tree_util.tree_map(lambda g: g / k, grads)
             loss = lsum / k
+            stats = jax.tree_util.tree_map(lambda c: c.sum(0), stats)
         if grad_exchange:
             from repro.collectives.xla import exchange_tree
             grads = exchange_tree(grads, axis, grad_exchange)
             n = jax.lax.axis_size(axis)
             grads = jax.tree_util.tree_map(lambda g: g / n, grads)
             loss = jax.lax.pmean(loss, axis)   # report the global-batch loss
+            stats = jax.lax.psum(stats, axis)
         new_params, new_opt = optimizer.update(grads, state["opt"],
                                                state["params"], lr)
-        return {"params": new_params, "opt": new_opt}, loss
+        new_state = {"params": new_params, "opt": new_opt}
+        if with_stats:
+            return new_state, loss, stats
+        return new_state, loss
 
     return train_step
 
@@ -71,7 +99,10 @@ def make_data_parallel_step(model, optimizer: Optimizer, mesh,
     The batch is sharded over ``"data"`` and the state replicated.  With
     ``grad_exchange=None`` GSPMD inserts XLA's all-reduce; ``"ring"`` /
     ``"doubling_halving"`` run the paper's explicit exchange under
-    shard_map.  -> (jitted step, replicated sharding, batch sharding).
+    shard_map.  The state is donated: the step writes the new parameters
+    and optimizer state over the old, so a caller must not read a state
+    once it has passed it in.  -> (jitted step, replicated sharding, batch
+    sharding).
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -81,9 +112,11 @@ def make_data_parallel_step(model, optimizer: Optimizer, mesh,
     if grad_exchange:
         step = jax.shard_map(step, mesh=mesh,
                              in_specs=(P(), P("data"), P()),
-                             out_specs=(P(), P()), check_vma=False)
+                             out_specs=step_outputs(model, P(), P()),
+                             check_vma=False)
     jitted = jax.jit(step, in_shardings=(rep, data, rep),
-                     out_shardings=(rep, rep))
+                     out_shardings=step_outputs(model, rep, rep),
+                     donate_argnums=(0,))
     return jitted, rep, data
 
 

@@ -36,7 +36,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.shapes import SHAPES
-from repro.engine.steps import (make_train_step, make_prefill,
+from repro.engine.steps import (make_train_step, make_prefill, step_outputs,
                                 make_decode_step, train_state_specs)
 from repro.launch import analysis
 from repro.launch.mesh import make_production_mesh, make_tiny_mesh
@@ -110,10 +110,10 @@ def build_jitted(cfg, shape, mesh, rules, *, window, microbatches: int = 1):
         batch_specs = model.input_specs(shape)
         batch_sh = tree_shardings(batch_specs, mesh, rules)
         step = make_train_step(model, adamw(), sh, microbatches=microbatches)
+        rep = NamedSharding(mesh, P())
         jitted = jax.jit(step,
-                         in_shardings=(state_sh, batch_sh,
-                                       NamedSharding(mesh, P())),
-                         out_shardings=(state_sh, NamedSharding(mesh, P())),
+                         in_shardings=(state_sh, batch_sh, rep),
+                         out_shardings=step_outputs(model, state_sh, rep),
                          donate_argnums=(0,))
         args = (pspec.abstract(state_specs), pspec.abstract(batch_specs),
                 jax.ShapeDtypeStruct((), jnp.float32))

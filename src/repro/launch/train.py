@@ -79,7 +79,7 @@ def main(argv=None):
     first_loss = None
     for i in range(step0, step0 + args.steps):
         batch = jax.device_put(data.batch(i, global_batch), data_sharding)
-        state, loss = jitted(state, batch, jnp.float32(sched(i)))
+        state, loss, *_ = jitted(state, batch, jnp.float32(sched(i)))
         if first_loss is None:
             first_loss = float(loss)
         if i % args.log_every == 0 or i == step0 + args.steps - 1:

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import math
 import os
 from functools import partial
 
@@ -131,15 +132,46 @@ def rope_freqs(d_half: int, theta: float):
     return 1.0 / (theta ** (np.arange(0, d_half, dtype=np.float32) / d_half))
 
 
-def apply_rope(x, positions, theta: float, sections: tuple[int, ...] | None = None):
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_freqs(d_half: int, theta: float, yarn) -> np.ndarray:
+    """YaRN's inverse frequencies (DeepSeek-V2's published rotary code):
+    frequencies that turn fewer than ``beta_slow`` times over the original
+    context are divided by ``factor``, those that turn more than
+    ``beta_fast`` times are kept, and a linear ramp blends between."""
+    dim = 2 * d_half
+
+    def turns_dim(turns):
+        return (dim * math.log(yarn.original_max_position
+                               / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(turns_dim(yarn.beta_fast)), 0)
+    hi = min(math.ceil(turns_dim(yarn.beta_slow)), dim - 1)
+    hi = hi + 0.001 if lo == hi else hi
+    ramp = np.clip((np.arange(d_half, dtype=np.float32) - lo) / (hi - lo),
+                   0, 1)
+    extra = rope_freqs(d_half, theta)
+    return (extra / yarn.factor * ramp + extra * (1 - ramp)).astype(
+        np.float32)
+
+
+def apply_rope(x, positions, theta: float, sections: tuple[int, ...] | None = None,
+               freqs=None, mag: float = 1.0):
     """Rotate ``x [..., S, H, D]`` by ``positions``.
 
     positions: ``[B, S]`` int for standard RoPE, or ``[B, S, 3]`` for M-RoPE
     with ``sections`` (t, h, w) splitting the half-dim (Qwen2-VL style).
+    ``freqs`` replaces the inverse frequencies of ``theta`` (YaRN), and
+    ``mag`` scales the cosines and sines.
     """
     d = x.shape[-1]
     d_half = d // 2
-    freqs = jnp.asarray(rope_freqs(d_half, theta))           # [d_half]
+    if freqs is None:
+        freqs = rope_freqs(d_half, theta)
+    freqs = jnp.asarray(freqs)                                # [d_half]
     if sections is None:
         ang = positions[..., None].astype(jnp.float32) * freqs  # [B,S,d_half]
     else:
@@ -150,8 +182,8 @@ def apply_rope(x, positions, theta: float, sections: tuple[int, ...] | None = No
                          * freqs[off:off + sec])
             off += sec
         ang = jnp.concatenate(parts, axis=-1)                 # [B,S,d_half]
-    cos = jnp.cos(ang)[:, :, None, :]                         # [B,S,1,d_half]
-    sin = jnp.sin(ang)[:, :, None, :]
+    cos = mag * jnp.cos(ang)[:, :, None, :]                   # [B,S,1,d_half]
+    sin = mag * jnp.sin(ang)[:, :, None, :]
     x1, x2 = x[..., :d_half], x[..., d_half:]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     out = jnp.concatenate(
@@ -170,17 +202,18 @@ def repeat_kv(k, n_rep: int):
 
 def chunked_attention(q, k, v, *, causal: bool = True,
                       window: int | None = None, q_chunk: int = 1024,
-                      q_offset: int = 0):
-    """softmax(QK^T/sqrt(d)) V without materializing [S, S].
+                      q_offset: int = 0, scale: float | None = None):
+    """softmax(QK^T * scale) V without materializing [S, S].
 
-    q: [B, Sq, H, D]; k, v: [B, Sk, H, D] (KV already GQA-repeated).
+    q, k: [B, Sq|Sk, H, D]; v: [B, Sk, H, Dv] (KV already GQA-repeated;
+    Dv may differ from D).  ``scale`` defaults to 1/sqrt(D).
     ``q_offset``: absolute position of q[0] (prefill continuation / decode).
     ``window``: sliding-window size (key j visible to query i iff
     i - window < j <= i).
     """
     b, sq, h, d = q.shape
-    sk = k.shape[1]
-    scale = 1.0 / np.sqrt(d)
+    sk, dv = k.shape[1], v.shape[-1]
+    scale = 1.0 / np.sqrt(d) if scale is None else scale
     if _UNROLL:
         # cost-measurement mode (dry-run depth variants): chunking does not
         # change flop/byte totals, so use one full-width chunk — the
@@ -219,18 +252,20 @@ def chunked_attention(q, k, v, *, causal: bool = True,
         else:
             out = jax.lax.map(lambda args: one_chunk(args[0], args[1]),
                               (jnp.arange(n_chunks), qs))
-        out = out.transpose(1, 0, 2, 3, 4).reshape(b, n_chunks * q_chunk, h, d)
+        out = out.transpose(1, 0, 2, 3, 4).reshape(b, n_chunks * q_chunk, h,
+                                                   dv)
         out = out[:, :sq] if pad else out
     return out
 
 
 def decode_attention(q, k_cache, v_cache, pos, *, window: int | None = None,
-                     repeated: bool = False):
+                     repeated: bool = False, scale: float | None = None):
     """One-token attention against a cache.
 
-    q: [B, 1, H, D]; caches: [B, S, Hkv, D] (GQA-repeated already iff
+    q: [B, 1, H, D]; caches: [B, S, Hkv, D|Dv] (GQA-repeated already iff
     ``repeated``); pos: [B] int32 — number of valid tokens already in the
-    cache (the new token occupies slot ``pos``).
+    cache (the new token occupies slot ``pos``).  ``scale`` defaults to
+    1/sqrt(D).
     """
     b, s, hkv, d = k_cache.shape
     h = q.shape[2]
@@ -239,7 +274,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int | None = None,
     else:
         k = repeat_kv(k_cache, h // hkv)
         v = repeat_kv(v_cache, h // hkv)
-    scale = 1.0 / np.sqrt(d)
+    scale = 1.0 / np.sqrt(d) if scale is None else scale
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale  # [B,H,1,S]
     kpos = jnp.arange(s)[None, :]                        # [1,S]

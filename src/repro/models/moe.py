@@ -1,4 +1,7 @@
-"""Mixture-of-Experts FFN with group-local sort-based capacity dispatch.
+"""Mixture-of-Experts FFNs: a capacity dispatch (``moe_ffn``) and a
+drop-free expert share (``moe_dropless``).
+
+Capacity dispatch, group-local and sort-based.
 
 GShard-style semantics: each batch row is a dispatch *group* with capacity
 C = ceil(S * top_k * cf / E).  Within a group, token->expert assignments are
@@ -16,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.models.layers import mlp
 from repro.models.spec import TensorSpec as TS
 
 
@@ -110,3 +114,112 @@ def moe_ffn(cfg: ModelConfig, p, x, sh):
     w = (gate.reshape(B, SK) * live.astype(jnp.float32)).astype(dt)
     out = (gathered * w[..., None]).reshape(B, S, K, D).sum(axis=2)
     return out.astype(dt), aux
+
+
+# --------------------------------------------------------------------------
+# Drop-free expert share (DeepSeek-V2 MoE; model-configs guide §4).
+#
+# The layer is told which experts it holds: ``experts_held`` of the
+# ``n_experts``, from ``expert_offset`` on (0 held: all).  Every token is
+# routed over all experts (a float32 softmax and a greedy top-k), and every
+# assignment that lands on a held expert is computed, however uneven the
+# routing, so no token is dropped.  The held assignments, sorted by expert,
+# run as one grouped matmul (``jax.lax.ragged_dot``) whose groups are the
+# experts' loads, so the matmuls' work follows the tokens routed here.  The
+# buffer is static: tokens × top_k rows, the most that can land here, of
+# which the rows past the held assignments are outside every group.  Its
+# price is the gathers, masks and activations over the whole buffer, a
+# memory pass of tokens × top_k × d_model elements each, whatever the load.
+# The shared experts run on every token once, and the sequence-wise
+# balance loss is over the full router.  On one chip the share runs without
+# its exchange: what the absent experts would add is left out.
+
+
+def dropless_specs(cfg: ModelConfig, n: int) -> dict:
+    Lx, D, F, E = n, cfg.d_model, cfg.d_ff, cfg.n_experts
+    held = cfg.experts_held or E
+    s = {
+        "router": TS((Lx, D, E), ("layers", "embed", None)),
+        "wi_gate": TS((Lx, held, D, F), ("layers", "experts", "embed", "mlp")),
+        "wi_up": TS((Lx, held, D, F), ("layers", "experts", "embed", "mlp")),
+        "wo": TS((Lx, held, F, D), ("layers", "experts", "mlp", "embed")),
+    }
+    if cfg.n_shared_experts:
+        Fs = F * cfg.n_shared_experts
+        s["shared"] = {
+            "wi_gate": TS((Lx, D, Fs), ("layers", "embed", "mlp")),
+            "wi_up": TS((Lx, D, Fs), ("layers", "embed", "mlp")),
+            "wo": TS((Lx, Fs, D), ("layers", "mlp", "embed"))}
+    return s
+
+
+@jax.custom_vjp
+def _permute(x, idx, inv):
+    """x[idx] for a permutation idx of x's rows, whose inverse is inv: the
+    backward pass gathers by inv where a plain gather's would scatter."""
+    return x.at[idx].get(mode="promise_in_bounds")
+
+
+def _permute_fwd(x, idx, inv):
+    return _permute(x, idx, inv), inv
+
+
+def _permute_bwd(inv, g):
+    return g.at[inv].get(mode="promise_in_bounds"), None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def route(cfg: ModelConfig, router, x):
+    """Float32 softmax over all experts and a greedy top-k.  x [B, S, D] ->
+    (gates [B, S, K], expert ids [B, S, K], sequence-wise balance loss)."""
+    E, K = cfg.n_experts, cfg.top_k
+    S = x.shape[1]
+    logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
+                        router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate, eid = jax.lax.top_k(probs, K)   # not renormalised, as published
+    # DeepSeek-V2's sequence-wise balance loss: each expert's share of a
+    # sequence's assignments (times E / K) against its mean probability.
+    picked = jnp.sum(jax.nn.one_hot(eid, E, dtype=jnp.float32), axis=(1, 2))
+    frac = picked * (E / (S * K))                                    # [B,E]
+    aux = jnp.mean(jnp.sum(frac * jnp.mean(probs, axis=1), axis=-1))
+    return gate, eid, aux
+
+
+def moe_dropless(cfg: ModelConfig, p, x):
+    """x [B, S, D] -> (out [B, S, D], balance loss, held expert loads
+    [experts_held] int32)."""
+    dt = x.dtype
+    B, S, D = x.shape
+    T, K = B * S, cfg.top_k
+    held = cfg.experts_held or cfg.n_experts
+    with jax.named_scope("moe.route"):
+        gate, eid, aux = route(cfg, p["router"], x)
+    with jax.named_scope("moe.dispatch"):
+        local = eid.reshape(T * K) - cfg.expert_offset
+        mine = (local >= 0) & (local < held)
+        key = jnp.where(mine, local, held)
+        loads = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
+                        dtype=jnp.int32)                           # [held]
+        order = jnp.argsort(key, stable=True)  # held ones first, by expert
+        inv = jnp.argsort(order)
+        live = (jnp.arange(T * K) < jnp.sum(loads))[:, None]
+        xk = jnp.repeat(x.reshape(T, D), K, axis=0)                # [T*K,D]
+        rows = jnp.where(live, _permute(xk, order, inv), 0)
+    with jax.named_scope("moe.experts"):
+        g = jax.lax.ragged_dot(rows, p["wi_gate"].astype(dt), loads)
+        u = jax.lax.ragged_dot(rows, p["wi_up"].astype(dt), loads)
+        y = jax.lax.ragged_dot(jax.nn.silu(g) * u, p["wo"].astype(dt), loads)
+        # Rows outside every group are not written by every backend.
+        y = jnp.where(live, y, 0)
+    with jax.named_scope("moe.combine"):
+        ya = _permute(y, inv, order).reshape(T, K, D)
+        w = jnp.where(mine, gate.reshape(T * K), 0).astype(dt)
+        out = jnp.einsum("tkd,tk->td", ya, w.reshape(T, K))
+    out = out.reshape(B, S, D)
+    if "shared" in p:
+        out = out + mlp(cfg, p["shared"], x)
+    return out, aux, loads

@@ -1,5 +1,7 @@
 """Decoder-only transformer family: dense GQA (qwen2.5-*, gemma, h2o-danube),
-MoE (qwen3-moe, dbrx) and VLM backbone (qwen2-vl, M-RoPE + vision stub).
+MoE (qwen3-moe, dbrx), latent attention with leading dense layers and a
+drop-free expert share (deepseek-v2-lite) and VLM backbone (qwen2-vl,
+M-RoPE + vision stub).
 
 Layers are scanned over stacked parameters (MaxText-style) to bound HLO size
 and compile time; the layer body is rematerialized for training.
@@ -16,6 +18,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.configs.shapes import InputShape
 from repro.models import layers as L
+from repro.models import mla
 from repro.models import moe as moe_lib
 from repro.models.spec import TensorSpec as TS, init_params
 
@@ -45,8 +48,8 @@ def attn_specs(cfg: ModelConfig, n: int) -> dict:
     return s
 
 
-def mlp_specs(cfg: ModelConfig, n: int) -> dict:
-    Lx, D, F = n, cfg.d_model, cfg.d_ff
+def mlp_specs(cfg: ModelConfig, n: int, d_ff: int | None = None) -> dict:
+    Lx, D, F = n, cfg.d_model, d_ff or cfg.d_ff
     if cfg.activation in ("silu", "geglu"):
         return {"wi_gate": TS((Lx, D, F), ("layers", "embed", "mlp")),
                 "wi_up": TS((Lx, D, F), ("layers", "embed", "mlp")),
@@ -123,19 +126,29 @@ class TransformerModel:
         self.cfg = cfg
 
     # ------------------------------------------------------------ specs ----
-    def param_specs(self) -> dict:
+    def _layer_specs(self, n: int, moe: bool) -> dict:
         cfg = self.cfg
-        n, D, V = cfg.n_layers, cfg.d_model, cfg.vocab_size
+        D = cfg.d_model
         layer: dict = {"ln1": _norm_specs(cfg, (n, D), ("layers", "embed")),
-                       "attn": attn_specs(cfg, n),
+                       "attn": (mla.mla_specs(cfg, n) if cfg.is_mla
+                                else attn_specs(cfg, n)),
                        "ln2": _norm_specs(cfg, (n, D), ("layers", "embed"))}
-        if cfg.is_moe:
+        if moe and cfg.moe_dropless:
+            layer["moe"] = moe_lib.dropless_specs(cfg, n)
+        elif moe:
             layer["moe"] = moe_lib.moe_specs(cfg, n)
         else:
-            layer["mlp"] = mlp_specs(cfg, n)
+            layer["mlp"] = mlp_specs(cfg, n, cfg.dense_d_ff)
+        return layer
+
+    def param_specs(self) -> dict:
+        cfg = self.cfg
+        nd, D, V = cfg.first_dense_layers, cfg.d_model, cfg.vocab_size
         p = {"embed": TS((V, D), ("vocab", "embed"), init="embed"),
              "final_norm": _norm_specs(cfg, (D,), ("embed",)),
-             "layers": layer}
+             "layers": self._layer_specs(cfg.n_layers - nd, cfg.is_moe)}
+        if nd:
+            p["dense_layers"] = self._layer_specs(nd, False)
         if not cfg.tie_embeddings:
             p["unembed"] = TS((V, D), ("vocab", "embed"), init="embed")
         return p
@@ -189,21 +202,33 @@ class TransformerModel:
     # ---------------------------------------------------------- forward ----
     def _layer(self, params_i, x, positions, sh, window, cache_i=None,
                pos=None):
+        """-> (x, balance loss, held expert loads or None, new cache)."""
         cfg = self.cfg
         h = L.apply_norm(cfg, x, params_i["ln1"])
-        attn_out, new_cache = attention(
-            cfg, params_i["attn"], h, positions, sh,
-            window=window, cache=cache_i, pos=pos)
+        if cfg.is_mla:
+            attn_out, new_cache = mla.attention(
+                cfg, params_i["attn"], h, positions, sh, cache=cache_i,
+                pos=pos)
+        else:
+            attn_out, new_cache = attention(
+                cfg, params_i["attn"], h, positions, sh,
+                window=window, cache=cache_i, pos=pos)
         x = x + attn_out
         h = L.apply_norm(cfg, x, params_i["ln2"])
-        if cfg.is_moe:
-            ffn_out, aux = moe_lib.moe_ffn(cfg, params_i["moe"], h, sh)
-        else:
+        loads = None
+        if "mlp" in params_i:
             ffn_out, aux = L.mlp(cfg, params_i["mlp"], h), 0.0
-        return x + ffn_out, aux, new_cache
+        elif cfg.moe_dropless:
+            ffn_out, aux, loads = moe_lib.moe_dropless(cfg, params_i["moe"],
+                                                       h)
+        else:
+            ffn_out, aux = moe_lib.moe_ffn(cfg, params_i["moe"], h, sh)
+        return x + ffn_out, aux, loads, new_cache
 
-    def forward(self, params, batch, sh=L.NO_SHARD, *, window=None):
-        """Teacher-forced logits over the whole sequence. Returns (logits, aux)."""
+    def _trunk(self, params, batch, sh, window):
+        """The layers over the embedded tokens, then the final norm.
+        -> (x, summed balance loss, held expert loads [layers, held] or
+        None)."""
         cfg = self.cfg
         x = self._embed(params, batch)
         x = sh(x, "batch", "seq", "embed")
@@ -212,24 +237,48 @@ class TransformerModel:
 
         def body(carry, params_i):
             x, aux = carry
-            x, aux_i, _ = self._layer(params_i, x, positions, sh, window)
-            return (x, aux + aux_i), None
+            x, aux_i, loads, _ = self._layer(params_i, x, positions, sh,
+                                             window)
+            return (x, aux + aux_i), loads
 
-        (x, aux), _ = L.scan_layers(body, (x, 0.0), params["layers"])
-        x = L.apply_norm(cfg, x, params["final_norm"])
+        carry = (x, 0.0)
+        if "dense_layers" in params:
+            carry, _ = L.scan_layers(body, carry, params["dense_layers"])
+        (x, aux), loads = L.scan_layers(body, carry, params["layers"])
+        return L.apply_norm(cfg, x, params["final_norm"]), aux, loads
+
+    def forward(self, params, batch, sh=L.NO_SHARD, *, window=None):
+        """Teacher-forced logits over the whole sequence. Returns (logits, aux)."""
+        x, aux, _ = self._trunk(params, batch, sh, window)
         logits = L.lm_logits(x, self._unembed(params))
         return sh(logits, "batch", "seq", "vocab"), aux
 
     def loss(self, params, batch, sh=L.NO_SHARD):
-        logits, aux = self.forward(params, batch, sh)
+        return self.loss_and_stats(params, batch, sh)[0]
+
+    @property
+    def step_stats(self) -> bool:
+        """Whether ``loss_and_stats`` reports anything: the held experts'
+        loads of the drop-free MoE."""
+        return self.cfg.moe_dropless
+
+    def loss_and_stats(self, params, batch, sh=L.NO_SHARD):
+        """-> (loss, {"moe_assignments_held": [MoE layers, held] int32}
+        for the drop-free MoE, else {})."""
+        x, aux, loads = self._trunk(params, batch, sh, None)
+        logits = sh(L.lm_logits(x, self._unembed(params)),
+                    "batch", "seq", "vocab")
         ce = L.softmax_cross_entropy(logits, batch["labels"])
-        return ce + 0.01 * aux
+        stats = {} if loads is None else {"moe_assignments_held": loads}
+        return ce + self.cfg.aux_loss_coef * aux, stats
 
     # ------------------------------------------------------------ serve ----
     def cache_specs(self, shape: InputShape, dtype=jnp.bfloat16) -> dict:
         cfg = self.cfg
         n = cfg.n_layers
         B, S = shape.global_batch, shape.seq_len
+        if cfg.is_mla:
+            return mla.cache_specs(cfg, n, B, S, dtype)
         kv = (n, B, S, cfg.n_kv_heads, cfg.d_head)
         axes = ("layers", "batch", "cache_seq", "kv_heads", "head_dim")
         return {"k": TS(kv, axes, dtype=dtype, init="zeros"),
@@ -252,17 +301,29 @@ class TransformerModel:
         window = window if window is not None else cfg.sliding_window
 
         def body(x, xs):
-            params_i, k_i, v_i = xs
-            x, _, new_cache = self._layer(params_i, x, positions, sh, window,
-                                          cache_i=(k_i, v_i), pos=pos)
+            params_i, cache_i = xs
+            x, _, _, new_cache = self._layer(params_i, x, positions, sh,
+                                             window, cache_i=cache_i,
+                                             pos=pos)
             return x, new_cache
 
-        x, (k_new, v_new) = L.scan_layers(
-            body, x, (params["layers"], cache["k"], cache["v"]),
-            checkpoint_body=False)
+        names = ("ckv", "kpe") if cfg.is_mla else ("k", "v")
+        nd = cfg.first_dense_layers
+        parts = []
+        for key, lo, hi in (("dense_layers", 0, nd),
+                            ("layers", nd, cfg.n_layers)):
+            if lo == hi:
+                continue
+            caches = tuple(cache[n] if (lo, hi) == (0, cfg.n_layers)
+                           else cache[n][lo:hi] for n in names)
+            x, new = L.scan_layers(body, x, (params[key], caches),
+                                   checkpoint_body=False)
+            parts.append(new)
         x = L.apply_norm(cfg, x, params["final_norm"])
         logits = L.lm_logits(x, self._unembed(params))
-        return logits, {"k": k_new, "v": v_new}
+        return logits, {n: jnp.concatenate([p[i] for p in parts])
+                        if len(parts) > 1 else parts[0][i]
+                        for i, n in enumerate(names)}
 
     # ------------------------------------------------------------ inputs ---
     def input_specs(self, shape: InputShape) -> dict:

@@ -2,6 +2,7 @@
 import pytest
 
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
+from repro.configs.base import Yarn
 
 EXPECTED = {
     "qwen2.5-3b": dict(family="dense", n_layers=36, d_model=2048, n_heads=16,
@@ -34,6 +35,16 @@ EXPECTED = {
     "qwen2.5-14b": dict(family="dense", n_layers=48, d_model=5120,
                         n_heads=40, n_kv_heads=8, d_ff=13824,
                         vocab_size=152064, qkv_bias=True),
+    "deepseek-v2-lite": dict(family="moe", n_layers=27, d_model=2048,
+                             n_heads=16, d_ff=1408, vocab_size=102400,
+                             kv_lora_rank=512, qk_nope_head_dim=128,
+                             qk_rope_head_dim=64, v_head_dim=128,
+                             n_experts=64, top_k=6, n_shared_experts=2,
+                             first_dense_layers=1,
+                             dense_d_ff=10944, rope_theta=10_000.0,
+                             rope_yarn=Yarn(40.0, 4096, 32.0, 1.0, 0.707,
+                                            0.707),
+                             tie_embeddings=False),
 }
 
 
@@ -61,13 +72,15 @@ def test_smoke_configs_are_reduced(arch):
 
 @pytest.mark.parametrize("arch", ["qwen2.5-3b", "qwen2.5-14b", "gemma-2b",
                                   "mamba2-780m", "jamba-v0.1-52b",
-                                  "dbrx-132b", "qwen3-moe-30b-a3b"])
+                                  "dbrx-132b", "qwen3-moe-30b-a3b",
+                                  "deepseek-v2-lite"])
 def test_param_counts_in_expected_range(arch):
     """Full-config parameter counts should be near the advertised sizes."""
     bounds = {"qwen2.5-3b": (2.5e9, 4e9), "qwen2.5-14b": (12e9, 16e9),
               "gemma-2b": (2e9, 3.2e9), "mamba2-780m": (0.6e9, 1.0e9),
               "jamba-v0.1-52b": (45e9, 60e9), "dbrx-132b": (110e9, 145e9),
-              "qwen3-moe-30b-a3b": (25e9, 35e9)}
+              "qwen3-moe-30b-a3b": (25e9, 35e9),
+              "deepseek-v2-lite": (15.5e9, 16e9)}
     n = get_config(arch).param_count()
     lo, hi = bounds[arch]
     assert lo <= n <= hi, (arch, n)

@@ -15,7 +15,8 @@ S = 24
 
 
 @pytest.mark.parametrize("arch", ["qwen2.5-3b", "gemma-2b", "mamba2-780m",
-                                  "jamba-v0.1-52b", "qwen3-moe-30b-a3b"])
+                                  "jamba-v0.1-52b", "qwen3-moe-30b-a3b",
+                                  "deepseek-v2-lite"])
 def test_decode_matches_forward(arch):
     import dataclasses
     cfg = get_smoke_config(arch)
